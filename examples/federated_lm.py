@@ -131,5 +131,8 @@ if __name__ == "__main__":
                     help="restore the latest checkpoint under --ckpt "
                          "before running (no-op when none exists yet)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main(rounds=args.rounds, backends=tuple(args.backends),
          ckpt=args.ckpt, resume=args.resume)

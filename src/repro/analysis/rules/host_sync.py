@@ -42,12 +42,11 @@ _TRACING_WRAPPERS = {
     "jax.lax.scan", "jax.lax.map", "jax.lax.fori_loop", "jax.lax.while_loop",
     "jax.lax.cond", "jax.lax.switch", "jax.lax.associative_scan",
     "jax.experimental.shard_map.shard_map", "jax.shard_map",
-    "repro.jax_compat.shard_map",
     "jax.experimental.pallas.pallas_call", "pl.pallas_call",
     "jax.make_jaxpr", "jax.eval_shape",
 }
-# Unqualified names that count as wrappers too (e.g. the jax_compat
-# re-export ``from repro.jax_compat import shard_map``).
+# Unqualified names that count as wrappers too (e.g.
+# ``from jax import shard_map``).
 _WRAPPER_TAILS = {"shard_map", "pallas_call"}
 
 _SYNC_CALLS = {"float"}

@@ -9,7 +9,7 @@ frozen ``RoundResult``s as the host and compiled backends.
 
 Mapping (DESIGN.md §3b):
 
-- the ``pod`` mesh axis is *manual* (``jax_compat.shard_map``); the K
+- the ``pod`` mesh axis is *manual* (``jax.shard_map``); the K
   clients are blocked over the pods (K/P clients per pod, vmapped
   locally), so one pod process == one block of independently evolving
   client replicas;
@@ -40,7 +40,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.selection import selection_weights
 from repro.engine.base import Engine, MaskSelectionMixin
@@ -70,6 +70,12 @@ class ScaleoutEngine(MaskSelectionMixin, Engine):
                 f"n_clients={cfg.n_clients} must be divisible by the pod axis "
                 f"({self.n_pods}) so clients block evenly over pods"
             )
+        # the client data stacks live on the mesh, K/P clients per pod,
+        # so no round copies them out of the first device
+        on_pods = NamedSharding(self.mesh, P("pod"))
+        self.xs, self.ys, self.mask = jax.device_put(
+            (self.xs, self.ys, self.mask), on_pods
+        )
         self._sizes_j = jnp.asarray(self.sizes, jnp.float32)
         # aggregate() installs host (device_get) params every round; start
         # from host params too, or the round-0 poll/evaluate compile for a
@@ -91,7 +97,6 @@ class ScaleoutEngine(MaskSelectionMixin, Engine):
     def _build_scaleout_round(self) -> None:
         from repro.federated.client import local_train
         from repro.federated.scaleout import stack_for_clients
-        from repro.jax_compat import shard_map
 
         self._stack_for_clients = stack_for_clients
 
@@ -114,11 +119,14 @@ class ScaleoutEngine(MaskSelectionMixin, Engine):
             # then the all-reduce over pods: θ ← psum_pod Σ_block w_i θ_i.
             # Unselected clients (w=0) contribute exact zeros but still
             # receive the aggregated model (psum is replicated over pod).
+            # The weighted sum is elementwise, as in fedavg: a dot here
+            # would run on the TPU's MXU at its default bf16 precision.
+            def wsum(s):
+                wexp = w.reshape((-1,) + (1,) * (s.ndim - 1))
+                return jnp.sum(s.astype(jnp.float32) * wexp, axis=0)
+
             agg = jax.tree.map(
-                lambda s: jax.lax.psum(
-                    jnp.tensordot(w, s.astype(jnp.float32), axes=1), "pod"
-                ).astype(s.dtype),
-                ends,
+                lambda s: jax.lax.psum(wsum(s), "pod").astype(s.dtype), ends
             )
             return agg, losses
 
@@ -126,7 +134,7 @@ class ScaleoutEngine(MaskSelectionMixin, Engine):
         pspec = jax.tree.map(lambda _: pod, self.params)
         rspec = jax.tree.map(lambda _: P(), self.params)
         self._round_fn = jax.jit(
-            shard_map(
+            jax.shard_map(
                 body,
                 mesh=self.mesh,
                 in_specs=(pspec, pod, pod, pod, pod, pod, pod),

@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.jax_compat import shard_map
-
 from repro.models.transformer import loss_fn
 
 __all__ = ["make_federated_round", "stack_for_clients"]
@@ -119,7 +117,7 @@ def make_federated_round(cfg, mesh, lr: float, local_steps: int = 4,
         p_specs = jax.tree.map(lambda _: P("pod"), stacked_params)
         b_specs = jax.tree.map(lambda _: P("pod"), batch)
         if not compress_bits:
-            f = shard_map(
+            f = jax.shard_map(
                 body,
                 mesh=mesh,
                 in_specs=(p_specs, b_specs, P("pod")),
@@ -130,7 +128,7 @@ def make_federated_round(cfg, mesh, lr: float, local_steps: int = 4,
             return f(stacked_params, batch, weights)
         # compressed: train (manual pod, auto data/model), then aggregate
         # (manual pod+model: per-shard int8 quantize + gather + sum)
-        f_train = shard_map(
+        f_train = jax.shard_map(
             train_body,
             mesh=mesh,
             in_specs=(p_specs, b_specs, P("pod")),
@@ -158,7 +156,7 @@ def make_federated_round(cfg, mesh, lr: float, local_steps: int = 4,
             for sp, leaf in zip(flat_l, flat_p)
         ]
         mspecs = jax.tree.unflatten(jax.tree.structure(stacked_params), specs)
-        f_agg = shard_map(
+        f_agg = jax.shard_map(
             agg_body,
             mesh=mesh,
             in_specs=(mspecs, mspecs, P("pod")),

@@ -38,7 +38,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import INPUT_SHAPES, get_config, list_configs
 from repro.configs.inputs import decode_specs, input_specs, long_context_variant
-from repro.jax_compat import cost_analysis, set_mesh
 from repro.launch.mesh import make_production_mesh
 from repro.models.transformer import (
     cache_specs,
@@ -216,14 +215,14 @@ def _lower_cost(cfg, mesh, shape, policy_variant: str = "baseline"):
     fn, arg_specs, (in_shard, out_shard), donate = build_step(
         cfg, mesh, shape, policy_variant=policy_variant
     )
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = (
             jax.jit(fn, in_shardings=in_shard, out_shardings=out_shard,
                     donate_argnums=donate)
             .lower(*arg_specs)
             .compile()
         )
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     try:
         hlo = compiled.as_text()
     except Exception:
@@ -284,7 +283,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, record_hlo: bool = Fals
     fn, arg_specs, (in_shard, out_shard), donate = build_step(
         cfg, mesh, shape, policy_variant=policy_variant
     )
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(
             fn, in_shardings=in_shard, out_shardings=out_shard, donate_argnums=donate
         )
@@ -293,7 +292,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, record_hlo: bool = Fals
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
     mem = compiled.memory_analysis()
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     try:
         hlo = compiled.as_text()
     except Exception:
@@ -381,7 +380,7 @@ def run_federated(arch: str, local_steps: int = 4, batch_per_client: int = 128,
     round_fn = make_scaleout_round(cfg, mesh, lr=1e-3, local_steps=local_steps,
                                    compress_bits=compress_bits)
     t0 = time.time()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(
             round_fn,
             in_shardings=(pshard, bshard, wshard),
@@ -391,7 +390,7 @@ def run_federated(arch: str, local_steps: int = 4, batch_per_client: int = 128,
         lowered = jitted.lower(stacked_shapes, batch, w)
         compiled = lowered.compile()
     mem = compiled.memory_analysis()
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     rec = {
         "arch": arch,

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from repro.checkpoint import load_checkpoint, save_checkpoint
 from repro.configs import get_config
 from repro.data.synthetic import make_token_stream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import init_transformer, loss_fn
 from repro.optim import adamw, clip_by_global_norm, chain, warmup_cosine
 from repro.optim.optimizers import apply_updates
@@ -54,6 +55,7 @@ def main():
                          "from the stored step")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     if cfg.input_mode != "tokens":

@@ -30,7 +30,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.jax_compat import shard_map
 from repro.models import attention as attn
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
@@ -289,7 +288,7 @@ def _run_moe(p_mlp, cfg, x, mesh):
                 out2d = out2d + moe_mod._shared_expert(pl, cfg, x2d)
             return out2d.reshape(b, s, d), aux
 
-        return shard_map(
+        return jax.shard_map(
             ep_block, mesh=mesh, in_specs=(pspec, xspec), out_specs=(xspec, P()),
             check_vma=False,
         )(p_mlp, x)
@@ -325,7 +324,7 @@ def _run_moe(p_mlp, cfg, x, mesh):
                 out2d = out2d + moe_mod._shared_expert(pl, cfg, x2d)
             return out2d.reshape(b, s, d), aux
 
-        return shard_map(
+        return jax.shard_map(
             tp_block, mesh=mesh, in_specs=(pspec, xspec), out_specs=(xspec, P()),
             check_vma=False,
         )(p_mlp, x)
@@ -358,7 +357,7 @@ def _run_moe(p_mlp, cfg, x, mesh):
     # +37% — a net wall-time regression (≈87 ms redundant compute vs
     # ≈118 ms TP+all-reduce per layer on v5e napkin numbers).  Redundant
     # compute beats communication for this thin (d_ff=2048) layer.
-    return shard_map(
+    return jax.shard_map(
         block, mesh=mesh, in_specs=(pspec, xspec), out_specs=(xspec, P()),
         check_vma=False,
     )(p_mlp, x)

@@ -16,10 +16,10 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from repro.configs import get_config
 from repro.configs.base import InputShape
-from repro.jax_compat import cost_analysis, set_mesh
+from repro.launch.mesh import make_host_mesh
 from repro.launch.dryrun import build_step, collective_bytes
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_host_mesh(data=2, model=4)
 cases = [
     ("qwen3-14b", InputShape("t", 256, 8, "train")),
     ("deepseek-v3-671b", InputShape("t", 256, 8, "train")),
@@ -32,10 +32,10 @@ for arch, shape in cases:
         from dataclasses import replace
         cfg = replace(cfg, moe=replace(cfg.moe, impl="capacity"))
     fn, arg_specs, (ins, outs), donate = build_step(cfg, mesh, shape)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(fn, in_shardings=ins, out_shardings=outs,
                            donate_argnums=donate).lower(*arg_specs).compile()
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     mem = compiled.memory_analysis()
     coll = collective_bytes(compiled.as_text())
     assert cost.get("flops", 0) > 0, (arch, cost)

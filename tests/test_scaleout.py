@@ -19,13 +19,13 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_config
-from repro.jax_compat import set_mesh
 from repro.configs.inputs import dummy_batch
+from repro.launch.mesh import make_host_mesh
 from repro.federated.scaleout import make_federated_round, stack_for_clients
 from repro.models.transformer import init_transformer, loss_fn
 
 cfg = get_config("qwen3-14b", reduced=True)
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_host_mesh(data=2, model=2, pod=2)
 params = init_transformer(jax.random.PRNGKey(0), cfg)
 n_pods = 2
 B, S = 4, 64
@@ -36,7 +36,7 @@ weights = jnp.asarray([0.25, 0.75], jnp.float32)
 
 round_fn = make_federated_round(cfg, mesh, lr=0.05, local_steps=3)
 stacked = stack_for_clients(params, n_pods)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     new_stacked, losses = jax.jit(round_fn)(stacked, batch, weights)
 
 # oracle: train each client independently on one device, average by hand
@@ -61,7 +61,7 @@ diff = [float(jnp.max(jnp.abs(a[0].astype(jnp.float32) - a[1].astype(jnp.float32
 assert max(diff) < 1e-6, "aggregated params must be identical across clients"
 
 # zero-weight client is excluded: w=(0,1) → result == client 1 alone
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     only1, _ = jax.jit(round_fn)(stack_for_clients(params, 2), batch,
                                  jnp.asarray([0.0, 1.0], jnp.float32))
 got1 = jax.tree.map(lambda a: a[0], only1)
@@ -72,7 +72,7 @@ assert losses.shape == (2,) and bool(jnp.all(jnp.isfinite(losses)))
 
 # compressed (int8 delta) aggregation tracks the exact result
 round_q8 = make_federated_round(cfg, mesh, lr=0.05, local_steps=3, compress_bits=8)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     new_q8, _ = jax.jit(round_q8)(stack_for_clients(params, 2), batch, weights)
 got_q8 = jax.tree.map(lambda a: a[0], new_q8)
 rel = []
