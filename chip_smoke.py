@@ -12,7 +12,8 @@ Everything runs in this one process, through ``make_engine(...)`` and
      the same clients every round and allclose params; the Hellinger
      strip behind FedLECC's clustering must be the compiled Pallas kernel;
   c. federated LM at published width: xlstm-125m (12 layers, d_model
-     768, vocab 50 304), K=16, m=4, 512-token sequences, 2 rounds;
+     768, vocab 50 304), K=16, m=4, 512-token sequences, 2 rounds, the
+     loss poll cut to 8 sequences per client (``LM_SIZES``);
   d. with ``--four-chips`` and nothing else: phase b's config on
      ``backend="scaleout"`` over a pod=4 mesh of four chips, checked
      against ``backend="compiled"`` on one chip.
@@ -131,12 +132,20 @@ def paper_round(*, n_train: int = 60_000, n_test: int = 10_000,
     print(f"  compiled test accuracy: {accs}")
 
 
-def lm_round(*, n_clients: int = 16, m: int = 4, seq_len: int = 512,
-             seqs_per_client: int = 32, batch_size: int = 8,
-             max_steps_cap: int = 2, rounds: int = 2, eval_samples: int = 4,
-             vocab: int = LM_VOCAB, task_kwargs=None) -> dict:
-    """Phase c: federated LM through the compiled backend.  Returns the
-    timings and peak device bytes (information, not metrics)."""
+# Phase c's sizes.  ``eval_samples`` (sequences each client scores in
+# the loss poll) is cut from the FLConfig default of 128 to 8: the poll
+# vmaps one forward over all K clients and holds K x eval_samples x 512
+# x 50 304 float32 logits at once, which the v5e compiler refuses past
+# 8 per client (16 needs a 26.4 GB buffer, 128 needs 211 GB).
+LM_SIZES = dict(n_clients=16, m=4, seq_len=512, seqs_per_client=32,
+                batch_size=8, max_steps_cap=2, rounds=2, eval_samples=8)
+
+
+def lm_engine(*, n_clients: int, m: int, seq_len: int, seqs_per_client: int,
+              batch_size: int, max_steps_cap: int, rounds: int,
+              eval_samples: int, vocab: int = LM_VOCAB, task_kwargs=None):
+    """Phase c's engine: federated LM through the compiled backend, on
+    token streams from the seed."""
     task_kwargs = task_kwargs or {"model": "xlstm-125m", "reduced": False}
     train = make_token_stream(n_clients * seqs_per_client, seq_len, vocab, seed=0)
     test = make_token_stream(2 * batch_size, seq_len, vocab, seed=1)
@@ -146,14 +155,51 @@ def lm_round(*, n_clients: int = 16, m: int = 4, seq_len: int = 512,
         max_steps_cap=max_steps_cap, eval_samples=eval_samples,
         eval_every=1, seed=0,
     )
+    return make_engine(cfg, train, test, n_classes=vocab)
+
+
+def lm_programs(engine) -> dict:
+    """The LM round's two largest programs with arguments of the shapes
+    a round passes them: the K-wide loss poll and the m-client cohort
+    train."""
+    key = jax.random.PRNGKey(0)
+    cohort = jnp.arange(engine.cfg.m, dtype=jnp.int32)
+    return {
+        "loss poll": (engine._poll_losses,
+                      (engine.params, engine.xs, engine.ys, engine.mask, key)),
+        "cohort train": (engine._train_cohort, (engine.params, cohort, key)),
+    }
+
+
+def program_bytes(fn, args) -> dict:
+    """Device bytes the compiler plans for one program: arguments,
+    outputs and temporaries.  ``args`` may be arrays or shape specs."""
+    mem = fn.lower(*args).compile().memory_analysis()
+    return {"arguments": mem.argument_size_in_bytes,
+            "outputs": mem.output_size_in_bytes,
+            "temporaries": mem.temp_size_in_bytes}
+
+
+def _peak_bytes():
+    return (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")
+
+
+def lm_round(**sizes) -> dict:
+    """Phase c: full-width xlstm-125m rounds on the compiled backend.
+    Returns the timings and device bytes (information, not metrics)."""
+    sizes = {**LM_SIZES, **sizes}
+    m, rounds = sizes["m"], sizes["rounds"]
+    live = sum(a.nbytes for a in jax.live_arrays())
     t0 = time.perf_counter()
-    engine = make_engine(cfg, train, test, n_classes=vocab)
+    engine = lm_engine(**sizes)
     build_s = time.perf_counter() - t0
     mc = engine.task.model_cfg
     n_params = sum(int(x.size) for x in jax.tree.leaves(engine.params))
     print(f"[c] {mc.name}: {mc.n_layers} layers, d_model {mc.d_model}, "
-          f"vocab {mc.vocab}, {n_params:,} params; K={n_clients} m={m} "
-          f"seq {seq_len} batch {batch_size} steps<={max_steps_cap}")
+          f"vocab {mc.vocab}, {n_params:,} params; K={sizes['n_clients']} "
+          f"m={m} seq {sizes['seq_len']} batch {sizes['batch_size']} "
+          f"steps<={sizes['max_steps_cap']} "
+          f"polled sequences/client {sizes['eval_samples']}")
     before = engine.params
     round_s, results = [], []
     it = engine.rounds()
@@ -166,11 +212,12 @@ def lm_round(*, n_clients: int = 16, m: int = 4, seq_len: int = 512,
     _require(all(np.isfinite(v) for v in losses), f"lm: test loss not finite: {losses}")
     moved = _max_abs_diff(before, engine.params)
     _require(moved > 0, "lm: params did not change")
-    info = {"params": n_params, "build_s": build_s, "round_s": round_s,
-            "test_loss": losses, "max_param_change": moved}
-    stats = jax.devices()[0].memory_stats() or {}
-    info["peak_bytes_in_use"] = stats.get("peak_bytes_in_use")
     # round 0 includes the compilation of every program of the round
+    info = {"params": n_params, "live_bytes_before": live, "build_s": build_s,
+            "round_s": round_s, "test_loss": losses, "max_param_change": moved,
+            "peak_bytes_in_use": _peak_bytes(),
+            "compiled_bytes": {name: program_bytes(fn, args)
+                               for name, (fn, args) in lm_programs(engine).items()}}
     print(f"  information, not metrics: {json.dumps(info)}")
     return info
 
@@ -221,16 +268,18 @@ def main(argv=None) -> None:
     if args.four_chips:
         four_chip_round()
     else:
-        from repro.core.hellinger import strip_backend
         from repro.kernels.hellinger.ops import hellinger_strip_pallas
 
         paper_round()
-        kernel, interpret = strip_backend()
-        compiled_strips = hellinger_strip_pallas._cache_size()
-        print(f"  hellinger strip: pallas={kernel} interpret={interpret} "
-              f"compiled variants={compiled_strips}")
-        _require(kernel and not interpret and compiled_strips > 0,
+        strips = hellinger_strip_pallas._cache_size()
+        panel = jnp.zeros((100, 10), jnp.float32)  # phase b's K x classes
+        custom = "tpu_custom_call" in hellinger_strip_pallas.lower(
+            panel, panel).compile().as_text()
+        print(f"  hellinger strip: {strips} Pallas variant(s) ran; "
+              f"compiled as a tpu_custom_call: {custom}")
+        _require(strips > 0 and custom,
                  "the Hellinger strip did not run as the compiled Pallas kernel")
+        print(f"  peak_bytes_in_use after phase b: {_peak_bytes()}")
         lm_round()
     print(json.dumps({"ok": True, "device": device}))
 

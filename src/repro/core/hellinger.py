@@ -38,7 +38,6 @@ __all__ = [
     "hellinger_matrix",
     "hellinger_rows",
     "hellinger_blocked",
-    "strip_backend",
     "average_hd",
     "dense_budget_bytes",
     "set_dense_budget_bytes",
@@ -147,19 +146,6 @@ def hellinger_rows(rows, hists) -> np.ndarray:
     return np.asarray(_strip_jit(rb, r))
 
 
-def strip_backend(use_kernel: bool | str = "auto") -> tuple[bool, bool]:
-    """``(kernel, interpret)`` for ``hellinger_blocked``'s strips:
-    ``"auto"`` picks the compiled Pallas kernel on TPU and the lax
-    matmul elsewhere; ``True`` forces the kernel (interpret mode off
-    TPU); ``False`` forces the lax matmul."""
-    on_tpu = jax.default_backend() == "tpu"
-    if use_kernel == "auto":
-        return on_tpu, False
-    if use_kernel:
-        return True, not on_tpu
-    return False, False
-
-
 def hellinger_blocked(
     hists,
     block: int = 4096,
@@ -191,7 +177,13 @@ def hellinger_blocked(
     r_host = _sqrt_rows(h)
     r = jnp.asarray(r_host)
 
-    kernel, interpret = strip_backend(use_kernel)
+    if use_kernel == "auto":
+        on_tpu = jax.default_backend() == "tpu"
+        kernel, interpret = on_tpu, False
+    elif use_kernel:
+        kernel, interpret = True, jax.default_backend() != "tpu"
+    else:
+        kernel, interpret = False, False
     if kernel:
         from repro.kernels.hellinger.ops import hellinger_strip_pallas
 

@@ -119,14 +119,11 @@ class ScaleoutEngine(MaskSelectionMixin, Engine):
             # then the all-reduce over pods: θ ← psum_pod Σ_block w_i θ_i.
             # Unselected clients (w=0) contribute exact zeros but still
             # receive the aggregated model (psum is replicated over pod).
-            # The weighted sum is elementwise, as in fedavg: a dot here
-            # would run on the TPU's MXU at its default bf16 precision.
-            def wsum(s):
-                wexp = w.reshape((-1,) + (1,) * (s.ndim - 1))
-                return jnp.sum(s.astype(jnp.float32) * wexp, axis=0)
-
             agg = jax.tree.map(
-                lambda s: jax.lax.psum(wsum(s), "pod").astype(s.dtype), ends
+                lambda s: jax.lax.psum(
+                    jnp.tensordot(w, s.astype(jnp.float32), axes=1), "pod"
+                ).astype(s.dtype),
+                ends,
             )
             return agg, losses
 

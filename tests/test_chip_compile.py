@@ -9,6 +9,9 @@ compilation cache is off around these compiles: an entry written for a
 described chip cannot be read back without one.
 """
 
+import importlib.util
+from pathlib import Path
+
 import pytest
 
 import jax
@@ -16,6 +19,7 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 V5E_HBM_BYTES = 16 * 10**9
+CHIP_SMOKE = Path(__file__).resolve().parents[1] / "chip_smoke.py"
 
 
 @pytest.fixture(scope="module")
@@ -80,3 +84,25 @@ def test_paper_cohort_train_compiles_for_v5e(one_chip):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert 0 < used < V5E_HBM_BYTES
+
+
+@pytest.fixture(scope="module")
+def full_width_lm():
+    """``chip_smoke.py`` phase c's engine, xlstm-125m at its published
+    width, and the two largest programs of its round."""
+    spec = importlib.util.spec_from_file_location("chip_smoke", CHIP_SMOKE)
+    cs = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(cs)
+    return cs, cs.lm_programs(cs.lm_engine(**cs.LM_SIZES))
+
+
+@pytest.mark.parametrize("program", ["loss poll", "cohort train"])
+def test_full_width_lm_program_fits_v5e(one_chip, full_width_lm, program):
+    """Phase c's loss poll (K=16 clients, 8 sequences each) and cohort
+    train (m=4) compile for one v5e and fit it."""
+    cs, programs = full_width_lm
+    fn, args = programs[program]
+    specs = jax.tree.map(lambda x: _spec(x.shape, x.dtype, one_chip), args)
+    used = cs.program_bytes(fn, specs)
+    print(f"{program}: {used}")
+    assert 0 < sum(used.values()) < V5E_HBM_BYTES
