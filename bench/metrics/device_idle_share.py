@@ -1,0 +1,12 @@
+"""Share of the traced window in which no operation ran on the device:
+1 - (union of device operation intervals) / window, averaged over the
+chips used.  Moves ``round_s``: host work the device waits for."""
+
+UNIT = "%"
+
+
+def read(ctx):
+    t = ctx["trace"]
+    if not t or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
