@@ -3,8 +3,6 @@
   bench_accuracy   — Table II   (final accuracy under severe label skew)
   bench_comm       — Table III  (communication overhead, MB)
   bench_rounds     — Fig 3      (rounds-to-target-accuracy, −22% claim)
-  bench_selection  — "lightweight selection" claim (μs per selection stage)
-  bench_kernels    — kernel substrate micro-benchmarks
   roofline         — EXPERIMENTS.md §Roofline from results/dryrun.jsonl
 
 ``python -m benchmarks.run`` executes all of them and prints

@@ -18,17 +18,12 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default=None,
-                    help="run a single bench: selection|kernels|accuracy|comm|rounds|roofline")
+                    help="run a single bench: accuracy|comm|rounds|roofline")
     args = ap.parse_args()
 
-    from benchmarks import (
-        bench_accuracy, bench_comm, bench_kernels, bench_rounds,
-        bench_selection, roofline,
-    )
+    from benchmarks import bench_accuracy, bench_comm, bench_rounds, roofline
 
     benches = {
-        "selection": bench_selection.main,
-        "kernels": bench_kernels.main,
         "accuracy": bench_accuracy.main,
         "comm": bench_comm.main,
         "rounds": bench_rounds.main,

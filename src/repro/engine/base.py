@@ -60,6 +60,7 @@ from repro.engine.config import (
     mask_backend_strategy_error,
 )
 from repro.engine.registry import STRATEGY_REGISTRY, mask_selection_strategies
+from repro.engine.trace import scope, span, step, to_host
 
 __all__ = [
     "Engine",
@@ -374,8 +375,9 @@ class Engine:
                 out = apply_fn(params, jnp.take(x, idx, axis=0))
                 return loss_fn(out, jnp.take(y, idx, axis=0), None)
 
-            keys = jax.random.split(key, xs.shape[0])
-            return jax.vmap(one)(xs, ys, mask, keys)
+            with scope("poll"):
+                keys = jax.random.split(key, xs.shape[0])
+                return jax.vmap(one)(xs, ys, mask, keys)
 
         self._poll_losses = jax.jit(_poll_losses, donate_argnums=())
 
@@ -398,8 +400,9 @@ class Engine:
                     out = apply_fn(params, jnp.take(x, idx, axis=0))
                     return loss_fn(out, jnp.take(y, idx, axis=0), None)
 
-                keys = jnp.take(jax.random.split(key, K), members, axis=0)
-                return jax.vmap(one)(xs, ys, mask, keys)
+                with scope("poll"):
+                    keys = jnp.take(jax.random.split(key, K), members, axis=0)
+                    return jax.vmap(one)(xs, ys, mask, keys)
 
             self._poll_subset = jax.jit(_poll_subset, donate_argnums=())
 
@@ -435,8 +438,9 @@ class Engine:
             if self.strategy.needs_losses:
                 members = self._pop_members
                 assert members is not None, "poll before begin_round"
-                xs, ys, mask = self._store.gather(members)
-                out[members] = np.asarray(
+                with span("gather"):
+                    xs, ys, mask = self._store.gather(members)
+                out[members] = to_host(
                     self._poll_subset(
                         self.params, xs, ys, mask,
                         jnp.asarray(members), key,
@@ -444,7 +448,7 @@ class Engine:
                 )
             return out
         if self.strategy.needs_losses:
-            return np.asarray(
+            return to_host(
                 self._poll_losses(self.params, self.xs, self.ys, self.mask, key)
             )
         return np.zeros(self.cfg.n_clients, np.float32)
@@ -544,7 +548,8 @@ class Engine:
             self.last_quant_error = qerr
 
     def evaluate(self) -> tuple[float, float]:
-        tl, ta = self._evaluate(self.params, self.test_x, self.test_y)
+        tl, ta = to_host(self._evaluate(self.params, self.test_x, self.test_y),
+                         jax.device_get)
         return float(tl), float(ta)
 
     def eval_metrics(self) -> dict | None:
@@ -740,7 +745,8 @@ class Engine:
         for t in self.trackers:
             t.log_round(result)
         if allow_save and self.checkpointer is not None:
-            self.checkpointer.maybe_save(self, result.round)
+            with span("save"):
+                self.checkpointer.maybe_save(self, result.round)
 
     def close_trackers(self) -> None:
         for t in self.trackers:
@@ -764,45 +770,65 @@ class Engine:
 
         start = self._round
         for rnd in range(start, start + n_rounds):
-            key, k_poll, k_train = jax.random.split(key, 3)
+            # the span closes before the yield: the consumer's time
+            # between rounds lies outside every fl.* span
+            with step("round", rnd):
+                result, key = self._run_round(rnd, key)
+                self._emit(result, callback)
+            yield result
 
-            # population mode (DESIGN.md §15): pick the round's resident
-            # shards first — they bound what gets polled and gathered
-            pop_gate = None
-            if self._population is not None:
-                _, self._pop_members = self._population.begin_round(rnd)
-                pop_gate = self._population.resident_mask()
+    def _run_round(self, rnd: int, key: jax.Array) -> tuple[RoundResult, jax.Array]:
+        """One round of ``rounds()`` off the PRNG carry ``key``: poll,
+        select, train, aggregate and, on the cadence, evaluate; commits
+        the engine state and returns the round's record and the new
+        carry."""
+        cfg = self.cfg
+        key, k_poll, k_train = jax.random.split(key, 3)
 
+        # population mode (DESIGN.md §15): pick the round's resident
+        # shards first — they bound what gets polled and gathered
+        pop_gate = None
+        if self._population is not None:
+            _, self._pop_members = self._population.begin_round(rnd)
+            pop_gate = self._population.resident_mask()
+
+        with span("poll"):
             losses = self.poll_losses(rnd, k_poll)
-            if self._population is not None:
-                # fold raw polled member losses into the shard estimates
-                # *before* any gating zeroes them out
-                self._population.observe(losses)
-            # admission gate (DESIGN.md §10/§14/§15): offline,
-            # quarantined, or non-resident clients enter every selection
-            # path as -inf before select
-            losses = self._gated_losses(rnd, losses, extra_gate=pop_gate)
+        if self._population is not None:
+            # fold raw polled member losses into the shard estimates
+            # *before* any gating zeroes them out
+            self._population.observe(losses)
+        # admission gate (DESIGN.md §10/§14/§15): offline,
+        # quarantined, or non-resident clients enter every selection
+        # path as -inf before select
+        losses = self._gated_losses(rnd, losses, extra_gate=pop_gate)
+        with span("select"):
             sel = np.asarray(self.select(rnd, losses))
 
-            # deadline / availability outcome of the dispatched cohort:
-            # survivors keep their aggregation weight, dropped clients
-            # (offline, or stragglers past the deadline) are zeroed
-            if self._systems is not None:
-                outcome = self._systems.outcome(rnd, sel)
-                surv = outcome.survivors
-                n_reached = outcome.n_reached
-                sim_time, n_dropped = outcome.sim_time, outcome.n_dropped
+        # deadline / availability outcome of the dispatched cohort:
+        # survivors keep their aggregation weight, dropped clients
+        # (offline, or stragglers past the deadline) are zeroed
+        if self._systems is not None:
+            outcome = self._systems.outcome(rnd, sel)
+            surv = outcome.survivors
+            n_reached = outcome.n_reached
+            sim_time, n_dropped = outcome.sim_time, outcome.n_dropped
+            with span("train"):
                 payload, sel_losses = self.local_train(
                     rnd, sel, k_train, survivors=surv
                 )
-            else:
-                surv = sel
-                n_reached = len(sel)
-                sim_time, n_dropped = 0.0, 0
+        else:
+            surv = sel
+            n_reached = len(sel)
+            sim_time, n_dropped = 0.0, 0
+            with span("train"):
                 payload, sel_losses = self.local_train(rnd, sel, k_train)
 
-            n_faulty = n_quarantined = 0
-            uploaded: float = float(len(surv))
+        n_faulty = n_quarantined = 0
+        uploaded: float = float(len(surv))
+        # fault injection and the validation gate are part of the
+        # aggregation stage
+        with span("aggregate"):
             if self._faults is not None:
                 # quarantined clients picked anyway (loss-blind
                 # strategies) are dropped like stragglers, before their
@@ -842,64 +868,64 @@ class Engine:
             else:
                 self.aggregate(rnd, sel, payload)
 
-            # population mode polls only the resident members; everyone
-            # else is free on the ledger too
-            n_polled = (
-                None if self._pop_members is None else len(self._pop_members)
+        # population mode polls only the resident members; everyone
+        # else is free on the ledger too
+        n_polled = (
+            None if self._pop_members is None else len(self._pop_members)
+        )
+        if self._systems is not None or self._faults is not None:
+            # the server observes survivor losses only
+            keep = np.isin(sel, surv)
+            mean_loss = _mean_loss(np.asarray(sel_losses)[keep])
+            self.comm_mb += self.comm.round_mb(
+                n_reached, self.strategy.needs_losses,
+                m_uploaded=uploaded, n_polled=n_polled,
             )
-            if self._systems is not None or self._faults is not None:
-                # the server observes survivor losses only
-                keep = np.isin(sel, surv)
-                mean_loss = _mean_loss(np.asarray(sel_losses)[keep])
-                self.comm_mb += self.comm.round_mb(
-                    n_reached, self.strategy.needs_losses,
-                    m_uploaded=uploaded, n_polled=n_polled,
-                )
-            else:
-                mean_loss = _mean_loss(sel_losses)
-                self.comm_mb += self.comm.round_mb(
-                    len(sel), self.strategy.needs_losses, n_polled=n_polled,
-                )
-            if self._systems is not None:
-                self.sim_clock += sim_time
+        else:
+            mean_loss = _mean_loss(sel_losses)
+            self.comm_mb += self.comm.round_mb(
+                len(sel), self.strategy.needs_losses, n_polled=n_polled,
+            )
+        if self._systems is not None:
+            self.sim_clock += sim_time
 
-            # energy ledger (ROADMAP (q)): the dispatched-and-online
-            # cohort spends its local-training charge; reported every
-            # round (not just evaluated ones) via RoundResult.metrics
-            energy = None
-            if self._systems is not None and self._systems.tracks_energy:
-                energy = self._systems.spend_energy(rnd, sel)
+        # energy ledger (ROADMAP (q)): the dispatched-and-online
+        # cohort spends its local-training charge; reported every
+        # round (not just evaluated ones) via RoundResult.metrics
+        energy = None
+        if self._systems is not None and self._systems.tracks_energy:
+            energy = self._systems.spend_energy(rnd, sel)
 
-            test_loss = test_acc = metrics = None
-            # absolute cadence keyed to the *configured* terminal round,
-            # so chunked / resumed rounds() calls evaluate on exactly the
-            # schedule one contiguous call would (a per-call final-round
-            # force-eval would make resumed histories diverge)
-            if rnd % cfg.eval_every == 0 or rnd == cfg.rounds - 1:
+        test_loss = test_acc = metrics = None
+        # absolute cadence keyed to the *configured* terminal round,
+        # so chunked / resumed rounds() calls evaluate on exactly the
+        # schedule one contiguous call would (a per-call final-round
+        # force-eval would make resumed histories diverge)
+        if rnd % cfg.eval_every == 0 or rnd == cfg.rounds - 1:
+            with span("evaluate"):
                 test_loss, test_acc = self.evaluate()
                 metrics = self.eval_metrics()
-            if energy is not None:
-                metrics = {**(metrics or {}), **energy}
+        if energy is not None:
+            metrics = {**(metrics or {}), **energy}
 
-            self._round = rnd + 1
-            self._key = key
-            result = RoundResult(
-                round=rnd,
-                selected=tuple(int(i) for i in surv),
-                mean_selected_loss=mean_loss,
-                comm_mb=float(self.comm_mb),
-                test_loss=test_loss,
-                test_acc=test_acc,
-                sim_time=float(sim_time),
-                sim_clock=float(self.sim_clock),
-                n_dropped=int(n_dropped),
-                metrics=metrics,
-                params_version=rnd + 1,
-                n_faulty=int(n_faulty),
-                n_quarantined=int(n_quarantined),
-            )
-            self._emit(result, callback)
-            yield result
+        self._round = rnd + 1
+        self._key = key
+        result = RoundResult(
+            round=rnd,
+            selected=tuple(int(i) for i in surv),
+            mean_selected_loss=mean_loss,
+            comm_mb=float(self.comm_mb),
+            test_loss=test_loss,
+            test_acc=test_acc,
+            sim_time=float(sim_time),
+            sim_clock=float(self.sim_clock),
+            n_dropped=int(n_dropped),
+            metrics=metrics,
+            params_version=rnd + 1,
+            n_faulty=int(n_faulty),
+            n_quarantined=int(n_quarantined),
+        )
+        return result, key
 
     def run(self, rounds: int | None = None, log_every: int = 0) -> dict[str, list]:
         """Legacy consumer: drain ``rounds()`` and return the history
@@ -945,7 +971,7 @@ class MaskSelectionMixin:
             raise ValueError(mask_backend_aggregator_error(self.cfg.aggregator))
 
     def select(self, rnd: int, losses: np.ndarray) -> np.ndarray:
-        mask = np.asarray(self.strategy.select_mask_jax(losses, self.rng))
+        mask = to_host(self.strategy.select_mask_jax(losses, self.rng))
         return np.where(mask)[0]
 
 

@@ -57,6 +57,7 @@ import numpy as np
 
 from repro.core.selection import selection_weights
 from repro.engine.base import Engine, MaskSelectionMixin
+from repro.engine.trace import scope, span, to_host
 from repro.federated.client import local_train
 
 __all__ = ["CompiledEngine", "make_scaleout_round"]
@@ -98,8 +99,9 @@ class CompiledEngine(MaskSelectionMixin, Engine):
         vmapped = jax.vmap(_one_client, in_axes=(None, 0, 0, 0, 0, 0))
 
         def _train_all(params, xs, ys, mask, taus, key):
-            keys = self._client_keys(key, jnp.arange(K))
-            return vmapped(params, xs, ys, mask, taus, keys)
+            with scope("train"):
+                keys = self._client_keys(key, jnp.arange(K))
+                return vmapped(params, xs, ys, mask, taus, keys)
 
         self._train_all = jax.jit(_train_all, donate_argnums=())
 
@@ -108,15 +110,16 @@ class CompiledEngine(MaskSelectionMixin, Engine):
             shape is static (m = cfg.m), so the gathers and the vmap keep
             one compiled graph across rounds — the no-retrace guard test
             pins this."""
-            keys = self._client_keys(key, idx)
-            return vmapped(
-                params,
-                jnp.take(self.xs, idx, axis=0),
-                jnp.take(self.ys, idx, axis=0),
-                jnp.take(self.mask, idx, axis=0),
-                jnp.take(self._taus_j, idx),
-                keys,
-            )
+            with scope("train"):
+                keys = self._client_keys(key, idx)
+                return vmapped(
+                    params,
+                    jnp.take(self.xs, idx, axis=0),
+                    jnp.take(self.ys, idx, axis=0),
+                    jnp.take(self.mask, idx, axis=0),
+                    jnp.take(self._taus_j, idx),
+                    keys,
+                )
 
         # raw body reused inside the fused round chunk (repro.engine.fused)
         self._cohort_train_raw = _cohort_train
@@ -129,8 +132,9 @@ class CompiledEngine(MaskSelectionMixin, Engine):
             derive *inside* the jit by global client index, exactly like
             ``_cohort_train``, so the same cohort trains bit-identically
             either way."""
-            keys = self._client_keys(key, idx)
-            return vmapped(params, xs, ys, mask, taus, keys)
+            with scope("train"):
+                keys = self._client_keys(key, idx)
+                return vmapped(params, xs, ys, mask, taus, keys)
 
         self._train_gathered = jax.jit(_train_gathered, donate_argnums=())
 
@@ -162,22 +166,23 @@ class CompiledEngine(MaskSelectionMixin, Engine):
         if self.cfg.compress_bits:
             self._qkey = self._quant_key(key, self.cfg.n_clients)
         if self._population is not None:
-            xs, ys, mask = self._store.gather(sel)
+            with span("gather"):
+                xs, ys, mask = self._store.gather(sel)
             stacked, losses = self._train_gathered(
                 self.params, xs, ys, mask,
                 jnp.asarray(self.taus[sel]),
                 jnp.asarray(sel, jnp.int32), key,
             )
-            return stacked, np.asarray(losses)
+            return stacked, to_host(losses)
         if self.cohort_gather:
             stacked, losses = self._train_cohort(
                 self.params, jnp.asarray(sel, jnp.int32), key
             )
-            return stacked, np.asarray(losses)
+            return stacked, to_host(losses)
         stacked, losses = self._train_all(
             self.params, self.xs, self.ys, self.mask, self._taus_j, key
         )
-        return stacked, np.asarray(losses)[sel]
+        return stacked, to_host(losses)[sel]
 
     # -- fault seam (DESIGN.md §14): the payload *is* the stack ---------
     def _payload_stack(self, payload):
@@ -221,7 +226,7 @@ class CompiledEngine(MaskSelectionMixin, Engine):
             new_params, qerr = self._compressed_agg(
                 cohort, self.params, jnp.take(w_full, sel_j), self._qkey
             )
-            self.last_quant_error = float(qerr)
+            self.last_quant_error = float(to_host(qerr))
             self.params = new_params
             return
 

@@ -63,6 +63,7 @@ from repro.core.selection import cohort_indices, selection_weights
 from repro.engine.base import RoundResult, _mean_loss
 from repro.engine.compiled import CompiledEngine
 from repro.engine.config import fused_aggregator_error, fused_strategy_error
+from repro.engine.trace import scope, span, step, to_host
 
 __all__ = ["FusedEngine"]
 
@@ -115,7 +116,8 @@ class FusedEngine(CompiledEngine):
             # split per round off the persisted carry
             key, k_poll, k_train = jax.random.split(key, 3)
             if needs_losses:
-                losses = poll(params, xs, ys, dmask, k_poll)
+                with scope("poll"):
+                    losses = poll(params, xs, ys, dmask, k_poll)
             else:
                 losses = jnp.zeros((K,), jnp.float32)
             # the availability / deadline traces (DESIGN.md §10) and the
@@ -132,12 +134,15 @@ class FusedEngine(CompiledEngine):
                 )
             if gate is not None:
                 losses = jnp.where(gate, losses, -jnp.inf)
-            # selection randomness rides a stream the eager path never
-            # consumes (fold tag K ≥ any client index), so deterministic
-            # strategies stay bit-compatible with the eager loop
-            mask = strategy.select_mask_traced(
-                losses, jax.random.fold_in(k_poll, K)
-            )
+            with scope("select"):
+                # selection randomness rides a stream the eager path
+                # never consumes (fold tag K ≥ any client index), so
+                # deterministic strategies stay bit-compatible with the
+                # eager loop
+                mask = strategy.select_mask_traced(
+                    losses, jax.random.fold_in(k_poll, K)
+                )
+                idx = cohort_indices(mask, m)
             # survivors: offline-at-dispatch and past-deadline clients
             # keep their static cohort slot but aggregate at weight zero
             final = mask
@@ -146,44 +151,46 @@ class FusedEngine(CompiledEngine):
             if faults:
                 final = final & inputs["admit"]
             arrivals = final  # pre-flag: the updates reaching the server
-            idx = cohort_indices(mask, m)
-            stacked, sel_losses = cohort_train(params, idx, k_train)
-            if faults:
-                # faults are upload properties: only rows whose upload
-                # reaches the server are injected (a zero-weight NaN row
-                # would still poison the mask-gated sum)
-                arrived_rows = jnp.take(arrivals, idx)
-                kind_rows = jnp.where(
-                    arrived_rows, jnp.take(inputs["fkind"], idx), -1
-                )
-                u_rows = jnp.take(inputs["fu"], idx)
-                stacked = fruntime.apply_traced(
-                    stacked, params, kind_rows, u_rows
-                )
-                if defended:
-                    stacked, flagged_rows, _ = fruntime.validate_traced(
-                        stacked, params, arrived_rows
+            with scope("train"):
+                stacked, sel_losses = cohort_train(params, idx, k_train)
+                if faults:
+                    # faults are upload properties: only rows whose
+                    # upload reaches the server are injected (a
+                    # zero-weight NaN row would still poison the
+                    # mask-gated sum)
+                    arrived_rows = jnp.take(arrivals, idx)
+                    kind_rows = jnp.where(
+                        arrived_rows, jnp.take(inputs["fkind"], idx), -1
                     )
-                    # quarantine takes effect at weight exactly zero
-                    flag_full = (
-                        jnp.zeros((K,), bool).at[idx].max(flagged_rows)
+                    u_rows = jnp.take(inputs["fu"], idx)
+                    stacked = fruntime.apply_traced(
+                        stacked, params, kind_rows, u_rows
                     )
-                    final = final & ~flag_full
-            w = jnp.take(selection_weights(final, sizes), idx)
-            if compress:
-                new_params, _ = compressed(
-                    stacked, params, w, self._quant_key(k_train, K)
-                )
-            else:
-                new_params = fedavg(stacked, w)
-            if systems or faults:
-                # nobody uploaded (or everyone was flagged) → the global
-                # model stands still (the all-zero weight vector would
-                # otherwise zero the params)
-                any_up = final.any()
-                new_params = jax.tree.map(
-                    lambda n, o: jnp.where(any_up, n, o), new_params, params
-                )
+                    if defended:
+                        stacked, flagged_rows, _ = fruntime.validate_traced(
+                            stacked, params, arrived_rows
+                        )
+                        # quarantine takes effect at weight exactly zero
+                        flag_full = (
+                            jnp.zeros((K,), bool).at[idx].max(flagged_rows)
+                        )
+                        final = final & ~flag_full
+            with scope("aggregate"):
+                w = jnp.take(selection_weights(final, sizes), idx)
+                if compress:
+                    new_params, _ = compressed(
+                        stacked, params, w, self._quant_key(k_train, K)
+                    )
+                else:
+                    new_params = fedavg(stacked, w)
+                if systems or faults:
+                    # nobody uploaded (or everyone was flagged) → the
+                    # global model stands still (the all-zero weight
+                    # vector would otherwise zero the params)
+                    any_up = final.any()
+                    new_params = jax.tree.map(
+                        lambda n, o: jnp.where(any_up, n, o), new_params, params
+                    )
             outs = (mask, final, sel_losses)
             if faults:
                 outs = outs + (arrivals,)
@@ -241,6 +248,90 @@ class FusedEngine(CompiledEngine):
             boundary = min(boundary, next_save)
         return max(1, min(cfg.fuse_rounds, boundary - rnd + 1))
 
+    def _unpack(self, rnd: int, length: int, masks, finals, sel_losses,
+                arrivals, fkind, fu) -> list[RoundResult]:
+        """The ``RoundResult`` of each round of the chunk that starts at
+        round ``rnd``, from its read-back outputs, with the comm, clock
+        and fault ledgers advanced round by round and the chunk-final
+        evaluation."""
+        cfg = self.cfg
+        results = []
+        for i in range(length):
+            r = rnd + i
+            sel = np.where(masks[i])[0]
+            surv = np.where(finals[i])[0]
+            n_faulty = n_quarantined = 0
+            uploaded: float | None = None
+            if self._faults is not None:
+                # per-round ledger replay off the scanned outputs:
+                # arrivals feed the health record, the host-side
+                # decisions give ground-truth fault counts + the
+                # partial-upload byte fractions
+                arr = np.where(arrivals[i])[0]
+                flagged = np.where(arrivals[i] & ~finals[i])[0]
+                self._faults.health.record(r, arr, flagged)
+                kind_r = np.where(arrivals[i], fkind[i], -1)
+                n_faulty = int((kind_r >= 0).sum())
+                n_quarantined = self._faults.health.n_quarantined(r)
+                uploaded = float(
+                    self._faults.upload_fractions(
+                        kind_r[arr], fu[i][arr]
+                    ).sum()
+                )
+            if self._systems is not None:
+                # same accounting core as the eager loop's outcome()
+                out = self._systems.outcome_from_mask(r, masks[i])
+                self.comm_mb += self.comm.round_mb(
+                    out.n_reached, self.strategy.needs_losses,
+                    m_uploaded=(
+                        len(surv) if uploaded is None else uploaded
+                    ),
+                )
+                self.sim_clock += out.sim_time
+                sim_time, n_dropped = out.sim_time, out.n_dropped
+                keep = finals[i][sel]  # survivor slots in cohort order
+                mean_loss = _mean_loss(sel_losses[i][keep])
+            elif self._faults is not None:
+                self.comm_mb += self.comm.round_mb(
+                    len(sel), self.strategy.needs_losses,
+                    m_uploaded=uploaded,
+                )
+                sim_time, n_dropped = 0.0, 0
+                keep = finals[i][sel]
+                mean_loss = _mean_loss(sel_losses[i][keep])
+            else:
+                self.comm_mb += self.comm.round_mb(
+                    len(sel), self.strategy.needs_losses
+                )
+                sim_time, n_dropped = 0.0, 0
+                mean_loss = _mean_loss(sel_losses[i])
+            test_loss = test_acc = metrics = None
+            # same absolute cadence as Engine.rounds(): eval-due
+            # rounds are always chunk-final (see _chunk_len), so the
+            # committed params are exactly the eager loop's
+            if i == length - 1 and (
+                r % cfg.eval_every == 0 or r == cfg.rounds - 1
+            ):
+                with span("evaluate"):
+                    test_loss, test_acc = self.evaluate()
+                    metrics = self.eval_metrics()
+            results.append(RoundResult(
+                round=r,
+                selected=tuple(int(j) for j in surv),
+                mean_selected_loss=mean_loss,
+                comm_mb=float(self.comm_mb),
+                test_loss=test_loss,
+                test_acc=test_acc,
+                sim_time=float(sim_time),
+                sim_clock=float(self.sim_clock),
+                n_dropped=int(n_dropped),
+                metrics=metrics,
+                params_version=r + 1,
+                n_faulty=int(n_faulty),
+                n_quarantined=int(n_quarantined),
+            ))
+        return results
+
     # -- the fused round loop ------------------------------------------
     def rounds(
         self,
@@ -258,128 +349,60 @@ class FusedEngine(CompiledEngine):
         end = start + n_rounds
         rnd = start
         while rnd < end:
-            length = self._chunk_len(rnd, end)
-            step = self._chunk_step(length)
-            fkind = fu = None
-            inputs: dict[str, np.ndarray] = {}
-            if self._systems is not None:
-                # exogenous availability / deadline-arrival traces for
-                # the chunk (host-deterministic per round, so the fused
-                # run sees exactly what the eager backends see)
-                inputs["avail"] = np.stack(
-                    [self._systems.available(rnd + i) for i in range(length)]
-                )
-                inputs["arrived"] = np.stack(
-                    [self._systems.arrived(rnd + i) for i in range(length)]
-                )
-            if self._faults is not None:
-                # per-round fault decisions are host-deterministic too;
-                # the admission gate is evaluated against the health
-                # ledger at *chunk start* — a fault flagged mid-chunk
-                # starts its quarantine at the next chunk boundary
-                # (eager runs quarantine one round earlier; DESIGN.md
-                # §14 documents the chunk-granular lag)
-                inputs["admit"] = np.stack(
-                    [self._faults.health.admitted(rnd + i) for i in range(length)]
-                )
-                decisions = [self._faults.decide(rnd + i) for i in range(length)]
-                fkind = np.stack([k for k, _ in decisions])
-                fu = np.stack([u for _, u in decisions])
-                inputs["fkind"] = fkind
-                inputs["fu"] = fu
-            if inputs:
-                outs = step(
-                    self.params, key,
-                    {k: jnp.asarray(v) for k, v in inputs.items()},
-                )
-            else:
-                outs = step(self.params, key)
+            # the chunk's inputs and its dispatch; the read-backs,
+            # unpacking and evaluation follow in spans of their own
+            with step("chunk", rnd):
+                length = self._chunk_len(rnd, end)
+                run = self._chunk_step(length)
+                fkind = fu = None
+                inputs: dict[str, np.ndarray] = {}
+                if self._systems is not None:
+                    # exogenous availability / deadline-arrival traces for
+                    # the chunk (host-deterministic per round, so the fused
+                    # run sees exactly what the eager backends see)
+                    inputs["avail"] = np.stack(
+                        [self._systems.available(rnd + i) for i in range(length)]
+                    )
+                    inputs["arrived"] = np.stack(
+                        [self._systems.arrived(rnd + i) for i in range(length)]
+                    )
+                if self._faults is not None:
+                    # per-round fault decisions are host-deterministic too;
+                    # the admission gate is evaluated against the health
+                    # ledger at *chunk start* — a fault flagged mid-chunk
+                    # starts its quarantine at the next chunk boundary
+                    # (eager runs quarantine one round earlier; DESIGN.md
+                    # §14 documents the chunk-granular lag)
+                    inputs["admit"] = np.stack(
+                        [self._faults.health.admitted(rnd + i) for i in range(length)]
+                    )
+                    decisions = [self._faults.decide(rnd + i) for i in range(length)]
+                    fkind = np.stack([k for k, _ in decisions])
+                    fu = np.stack([u for _, u in decisions])
+                    inputs["fkind"] = fkind
+                    inputs["fu"] = fu
+                if inputs:
+                    outs = run(
+                        self.params, key,
+                        {k: jnp.asarray(v) for k, v in inputs.items()},
+                    )
+                else:
+                    outs = run(self.params, key)
             if self._faults is not None:
                 params, key, masks, finals, sel_losses, arrivals = outs
-                arrivals = np.asarray(arrivals)
+                arrivals = to_host(arrivals)
             else:
                 params, key, masks, finals, sel_losses = outs
                 arrivals = None
             # commit the chunk before yielding anything from it
             self.params, self._key = params, key
             self._round = rnd + length
-            masks = np.asarray(masks)
-            finals = np.asarray(finals)
-            sel_losses = np.asarray(sel_losses)
-            results = []
-            for i in range(length):
-                r = rnd + i
-                sel = np.where(masks[i])[0]
-                surv = np.where(finals[i])[0]
-                n_faulty = n_quarantined = 0
-                uploaded: float | None = None
-                if self._faults is not None:
-                    # per-round ledger replay off the scanned outputs:
-                    # arrivals feed the health record, the host-side
-                    # decisions give ground-truth fault counts + the
-                    # partial-upload byte fractions
-                    arr = np.where(arrivals[i])[0]
-                    flagged = np.where(arrivals[i] & ~finals[i])[0]
-                    self._faults.health.record(r, arr, flagged)
-                    kind_r = np.where(arrivals[i], fkind[i], -1)
-                    n_faulty = int((kind_r >= 0).sum())
-                    n_quarantined = self._faults.health.n_quarantined(r)
-                    uploaded = float(
-                        self._faults.upload_fractions(
-                            kind_r[arr], fu[i][arr]
-                        ).sum()
-                    )
-                if self._systems is not None:
-                    # same accounting core as the eager loop's outcome()
-                    out = self._systems.outcome_from_mask(r, masks[i])
-                    self.comm_mb += self.comm.round_mb(
-                        out.n_reached, self.strategy.needs_losses,
-                        m_uploaded=(
-                            len(surv) if uploaded is None else uploaded
-                        ),
-                    )
-                    self.sim_clock += out.sim_time
-                    sim_time, n_dropped = out.sim_time, out.n_dropped
-                    keep = finals[i][sel]  # survivor slots in cohort order
-                    mean_loss = _mean_loss(sel_losses[i][keep])
-                elif self._faults is not None:
-                    self.comm_mb += self.comm.round_mb(
-                        len(sel), self.strategy.needs_losses,
-                        m_uploaded=uploaded,
-                    )
-                    sim_time, n_dropped = 0.0, 0
-                    keep = finals[i][sel]
-                    mean_loss = _mean_loss(sel_losses[i][keep])
-                else:
-                    self.comm_mb += self.comm.round_mb(
-                        len(sel), self.strategy.needs_losses
-                    )
-                    sim_time, n_dropped = 0.0, 0
-                    mean_loss = _mean_loss(sel_losses[i])
-                test_loss = test_acc = metrics = None
-                # same absolute cadence as Engine.rounds(): eval-due
-                # rounds are always chunk-final (see _chunk_len), so the
-                # committed params are exactly the eager loop's
-                if i == length - 1 and (
-                    r % cfg.eval_every == 0 or r == cfg.rounds - 1
-                ):
-                    test_loss, test_acc = self.evaluate()
-                    metrics = self.eval_metrics()
-                results.append(RoundResult(
-                    round=r,
-                    selected=tuple(int(j) for j in surv),
-                    mean_selected_loss=mean_loss,
-                    comm_mb=float(self.comm_mb),
-                    test_loss=test_loss,
-                    test_acc=test_acc,
-                    sim_time=float(sim_time),
-                    sim_clock=float(self.sim_clock),
-                    n_dropped=int(n_dropped),
-                    metrics=metrics,
-                    params_version=r + 1,
-                    n_faulty=int(n_faulty),
-                    n_quarantined=int(n_quarantined),
-                ))
+            masks = to_host(masks)
+            finals = to_host(finals)
+            sel_losses = to_host(sel_losses)
+            with span("unpack"):
+                results = self._unpack(rnd, length, masks, finals, sel_losses,
+                                       arrivals, fkind, fu)
             rnd += length
             for i, result in enumerate(results):
                 # checkpoints only at the chunk-final round: the engine

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.engine.base import Engine
+from repro.engine.trace import span, to_host
 from repro.federated.client import local_train
 
 __all__ = ["HostEngine"]
@@ -67,14 +68,15 @@ class HostEngine(Engine):
             # population mode (DESIGN.md §15): cohort rows come from the
             # host-side ClientStore — same values the device gather
             # would produce, so the round is bit-identical
-            xs, ys, mask = self._store.gather(sel)
+            with span("gather"):
+                xs, ys, mask = self._store.gather(sel)
         else:
             xs, ys, mask = self.xs[sel_j], self.ys[sel_j], self.mask[sel_j]
         stacked, local_losses = self._round_train(
             self.params, xs, ys, mask,
             jnp.asarray(self.taus[sel]), keys, h_sel,
         )
-        return (stacked, h_sel), np.asarray(local_losses)
+        return (stacked, h_sel), to_host(local_losses)
 
     # -- fault seam (DESIGN.md §14): payload rows are the cohort stack --
     def _payload_stack(self, payload):

@@ -44,6 +44,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.selection import selection_weights
 from repro.engine.base import Engine, MaskSelectionMixin
+from repro.engine.trace import to_host
 
 __all__ = ["ScaleoutEngine", "make_scaleout_round"]
 
@@ -159,7 +160,7 @@ class ScaleoutEngine(MaskSelectionMixin, Engine):
             self._stack_for_clients(self.params, K),
             self.xs, self.ys, self.mask, jnp.asarray(self.taus), keys, w,
         )
-        return new_params, np.asarray(losses)[sel]
+        return new_params, to_host(losses)[sel]
 
     def aggregate(self, rnd: int, sel: np.ndarray, payload,
                   survivors: np.ndarray | None = None) -> None:
@@ -168,7 +169,7 @@ class ScaleoutEngine(MaskSelectionMixin, Engine):
         # (poll/evaluate) never mix mesh-committed and uncommitted args.
         if survivors is not None and len(survivors) == 0:
             return  # all-zero psum (nobody uploaded): keep the old model
-        self.params = jax.device_get(payload)
+        self.params = to_host(payload, jax.device_get)
 
 
 def make_scaleout_round(model_cfg, mesh, lr: float, local_steps: int = 4,
