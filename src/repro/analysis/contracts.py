@@ -302,7 +302,7 @@ def _check_retrace(report: ContractReport) -> None:
         eng = _tiny_engine(backend="compiled")
         _drive_twice(eng)
         return _assert_budget(
-            eng, ("_train_cohort", "_masked_weights", "_poll_losses", "_evaluate")
+            eng, ("_train_cohort", "_aggregate_round", "_poll_losses", "_evaluate")
         )
 
     def fused() -> str:

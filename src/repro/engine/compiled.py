@@ -26,6 +26,16 @@ identical whichever cohort it runs in, and a ``CompiledEngine`` round is
 numerically identical to the ``HostEngine`` round for the same config —
 the cross-backend equivalence test asserts this.
 
+Aggregation is one compiled program a round (``_aggregate_round``,
+under the device scope ``aggregate``): the mask-gated weights, the
+cohort slice, and the bound aggregator's ``aggregate`` and
+``update_state`` in one dispatch, since each dispatch costs host time
+in which the device, with a few microseconds of work here, stands idle.
+Its variant (compressed or not, cohort-gathered or not, which
+aggregator) is fixed when the engine is built.  It donates nothing: the
+faults path's optimistic aggregation keeps the pre-round params and
+aggregator state and may call it again with the gate's survivors.
+
 ``FLConfig.compress_bits > 0`` swaps the fedavg aggregation for
 ``compressed_fedavg`` (``repro.federated.compression``): each selected
 client's delta is stochastically quantized to ``compress_bits`` before
@@ -138,18 +148,46 @@ class CompiledEngine(MaskSelectionMixin, Engine):
 
         self._train_gathered = jax.jit(_train_gathered, donate_argnums=())
 
-        def _masked_weights(mask):
-            return selection_weights(mask, self._sizes_j)
-
-        self._masked_weights = jax.jit(_masked_weights, donate_argnums=())
-
+        aggregator, gather = self.aggregator, self.cohort_gather
+        sizes, taus_all = self._sizes_j, self._taus_j
         if cfg.compress_bits:
             from repro.federated.compression import compressed_fedavg
 
-            self._compressed_agg = jax.jit(
-                partial(compressed_fedavg, bits=cfg.compress_bits),
-                donate_argnums=(),
-            )
+            compressed = partial(compressed_fedavg, bits=cfg.compress_bits)
+
+        def _aggregate_round(stacked, params, sel, mask, n_selected,
+                             agg_state, qkey):
+            """One round's aggregation as one program: the survivor mask
+            (K,) becomes mask-gated weights, sliced to the payload's rows,
+            and the bound aggregator reduces the payload.  Returns the new
+            params, the new aggregator state and, compressed, the mean
+            quantization error (else None)."""
+            with scope("aggregate"):
+                w_full = selection_weights(mask, sizes)
+                w_sel = jnp.take(w_full, sel)
+                if cfg.compress_bits:
+                    # Quantization models the *cohort's* upload, so the
+                    # reduce always runs over the m selected stacks
+                    # (taken from the all-K payload when cohort_gather
+                    # is off).
+                    cohort = stacked if gather else jax.tree.map(
+                        lambda s: jnp.take(s, sel, axis=0), stacked
+                    )
+                    new_params, qerr = compressed(cohort, params, w_sel, qkey)
+                    return new_params, agg_state, qerr
+                w = w_sel if gather else w_full
+                taus = (jnp.take(taus_all, sel) if gather
+                        else taus_all).astype(jnp.float32)
+                new_params = aggregator.aggregate(
+                    stacked, params, w, taus, agg_state, n_selected=n_selected,
+                )
+                new_state = aggregator.update_state(
+                    agg_state, stacked, params, w, n_selected=n_selected
+                )
+                return new_params, new_state, None
+
+        self._aggregate_round = jax.jit(_aggregate_round, donate_argnums=())
+        self._qkey = None
         self.last_quant_error: float | None = None
 
     @staticmethod
@@ -199,51 +237,28 @@ class CompiledEngine(MaskSelectionMixin, Engine):
 
     def aggregate(self, rnd: int, sel: np.ndarray, payload,
                   survivors: np.ndarray | None = None) -> None:
-        stacked = payload
-        sel_j = jnp.asarray(sel)
-        # The weight mask carries only the *survivors* (systems deadline
-        # drops, DESIGN.md §10): dropped cohort members keep their static
-        # payload slot but aggregate with exact weight zero — the same
-        # mask-gating mechanism that makes unselected clients free.
+        """Aggregate the round's payload into ``params`` (and the
+        aggregator state) with one dispatch of ``_aggregate_round``.
+
+        The weight mask carries only the *survivors* (systems deadline
+        drops, DESIGN.md §10): dropped cohort members keep their static
+        payload slot but aggregate with exact weight zero — the same
+        mask-gating mechanism that makes unselected clients free.  The
+        mask is built here with numpy, a (K,) bool whatever the survivor
+        count, so no round retraces the program.  Nothing is donated:
+        the faults path may restore the pre-round params and state and
+        call again with the gate's survivors."""
         weight_idx = sel if survivors is None else survivors
         if survivors is not None and len(survivors) == 0:
             return  # nobody uploaded: the global model stands still
-        mask = jnp.zeros((self.cfg.n_clients,), jnp.bool_).at[
-            jnp.asarray(weight_idx)
-        ].set(True)
-        w_full = self._masked_weights(mask)
-
-        if self.cfg.compress_bits:
-            # Quantization models the *cohort's* upload, so the reduce
-            # always runs over the m selected stacks (extracted from the
-            # all-K payload when cohort_gather is off).
-            if self.cohort_gather:
-                cohort = stacked
-            else:
-                cohort = jax.tree.map(
-                    lambda s: jnp.take(s, sel_j, axis=0), stacked
-                )
-            new_params, qerr = self._compressed_agg(
-                cohort, self.params, jnp.take(w_full, sel_j), self._qkey
-            )
+        mask = np.zeros((self.cfg.n_clients,), np.bool_)
+        mask[weight_idx] = True
+        self.params, self.agg_state, qerr = self._aggregate_round(
+            payload, self.params, np.asarray(sel, np.int32), mask,
+            np.int32(len(weight_idx)), self.agg_state, self._qkey,
+        )
+        if qerr is not None:
             self.last_quant_error = float(to_host(qerr))
-            self.params = new_params
-            return
-
-        if self.cohort_gather:
-            w = jnp.take(w_full, sel_j)
-            taus = jnp.asarray(self.taus[sel], jnp.float32)
-        else:
-            w = w_full
-            taus = jnp.asarray(self.taus, jnp.float32)
-        n_agg = len(weight_idx)
-        new_params = self.aggregator.aggregate(
-            stacked, self.params, w, taus, self.agg_state, n_selected=n_agg,
-        )
-        self.agg_state = self.aggregator.update_state(
-            self.agg_state, stacked, self.params, w, n_selected=n_agg
-        )
-        self.params = new_params
 
 
 def make_scaleout_round(model_cfg, mesh, lr: float, local_steps: int = 4,

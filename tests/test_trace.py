@@ -184,8 +184,8 @@ def _scopes(compiled_text: str) -> set[str]:
 
 def test_device_stages_are_named_scopes(data):
     """The fused chunk's operations carry the stage scopes in their HLO
-    metadata, and the compiled backend's poll and training programs
-    theirs."""
+    metadata, and the compiled backend's poll, training and aggregation
+    programs theirs."""
     eng = _engine(data, backend="compiled", fuse_rounds=2)
     key = eng._carry_key()
     chunk = eng._chunk_step(2).lower(eng.params, key).compile().as_text()
@@ -195,3 +195,9 @@ def test_device_stages_are_named_scopes(data):
     idx = jax.numpy.arange(4, dtype=jax.numpy.int32)
     train = eng._train_cohort.lower(eng.params, idx, key)
     assert "train" in _scopes(train.compile().as_text())
+    stacked = jax.tree.map(lambda p: jax.numpy.stack([p] * 4), eng.params)
+    mask = np.zeros((eng.cfg.n_clients,), np.bool_)
+    mask[:4] = True
+    agg = eng._aggregate_round.lower(stacked, eng.params, idx, mask,
+                                     np.int32(4), eng.agg_state, None)
+    assert "aggregate" in _scopes(agg.compile().as_text())
