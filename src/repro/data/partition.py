@@ -13,6 +13,9 @@ the alpha↔HD mapping depends on K and the class count.
 
 from __future__ import annotations
 
+import os
+from concurrent.futures import ThreadPoolExecutor
+
 import numpy as np
 
 from repro.core.hellinger import average_hd
@@ -74,6 +77,20 @@ def dirichlet_partition(
     return out
 
 
+def _class_shuffled_order(labels: np.ndarray, rng: np.random.Generator):
+    """Sample indices sorted by label, shuffled within each class (the
+    first draws of ``shard_partition``'s stream), and the classes' block
+    bounds in that order."""
+    order = np.argsort(labels, kind="stable")
+    blocks = []
+    for c in np.unique(labels):
+        block = order[labels[order] == c]
+        rng.shuffle(block)
+        blocks.append(block)
+    bounds = np.cumsum([0] + [len(b) for b in blocks])
+    return np.concatenate(blocks), bounds
+
+
 def shard_partition(
     labels: np.ndarray,
     n_clients: int,
@@ -93,14 +110,8 @@ def shard_partition(
     """
     rng = np.random.default_rng(seed)
     labels = np.asarray(labels)
-    order = np.argsort(labels, kind="stable")
     # shuffle within each class so shards are random samples of the class
-    out_order = []
-    for c in np.unique(labels):
-        block = order[labels[order] == c]
-        rng.shuffle(block)
-        out_order.append(block)
-    order = np.concatenate(out_order)
+    order, _ = _class_shuffled_order(labels, rng)
     n_shards = n_clients * shards_per_client
     shards = np.array_split(order, n_shards)
     perm = rng.permutation(n_shards)
@@ -111,6 +122,27 @@ def shard_partition(
     ]
 
 
+def _shard_histograms(labels: np.ndarray, n_clients: int,
+                      shards_per_client: int, n_classes: int,
+                      bounds: np.ndarray, rng: np.random.Generator) -> np.ndarray:
+    """``label_histograms(labels, shard_partition(...), n_classes)`` from
+    the class blocks' bounds alone: a shard of the label-sorted order
+    holds, of each class, the overlap of its span with the class's
+    block.  ``rng`` is the stream after the class shuffles."""
+    n_shards = n_clients * shards_per_client
+    q, r = divmod(len(labels), n_shards)
+    ends = np.cumsum(q + (np.arange(n_shards) < r))  # np.array_split's cuts
+    starts = ends - (q + (np.arange(n_shards) < r))
+    overlap = np.clip(np.minimum(ends[:, None], bounds[None, 1:])
+                      - np.maximum(starts[:, None], bounds[None, :-1]), 0, None)
+    counts = np.zeros((n_shards, n_classes), np.int64)
+    counts[:, np.unique(labels)] = overlap
+    perm = rng.permutation(n_shards)
+    h = counts[perm].reshape(n_clients, shards_per_client, n_classes).sum(1)
+    h = h.astype(np.float64)
+    return h / np.maximum(h.sum(1, keepdims=True), 1e-12)
+
+
 def calibrate_shards(
     labels: np.ndarray,
     n_clients: int,
@@ -118,11 +150,18 @@ def calibrate_shards(
     n_classes: int,
     seed: int = 0,
 ) -> int:
-    """Pick shards_per_client whose partition HD is closest to target."""
+    """Pick shards_per_client whose partition HD is closest to target.
+    The candidates' histograms come from one shuffle of the data
+    (``_shard_histograms``), so no candidate partition is built."""
+    labels = np.asarray(labels)
+    rng = np.random.default_rng(seed)
+    _, bounds = _class_shuffled_order(labels, rng)
+    after_shuffle = rng.bit_generator.state
     best, best_err = 1, float("inf")
     for s in (1, 2, 3, 4, 6, 8):
-        parts = shard_partition(labels, n_clients, s, seed=seed)
-        hd = float(average_hd(label_histograms(labels, parts, n_classes)))
+        rng.bit_generator.state = after_shuffle
+        hists = _shard_histograms(labels, n_clients, s, n_classes, bounds, rng)
+        hd = float(average_hd(hists))
         if abs(hd - target_hd) < best_err:
             best, best_err = s, abs(hd - target_hd)
     return best
@@ -196,19 +235,24 @@ def pack_clients(
     """Ragged per-client indices → stacked (K, N_max, ...) arrays + mask.
 
     Padding rows repeat each client's first sample and are masked out, so
-    vmapped code never sees garbage values.
+    vmapped code never sees garbage values.  Clients are copied on a
+    thread per core (``np.take`` releases the interpreter lock), since
+    at population scale the copy is gigabytes.
     """
     n_max = max(len(ix) for ix in client_idx)
     k = len(client_idx)
     xs = np.zeros((k, n_max) + x.shape[1:], dtype=x.dtype)
     ys = np.zeros((k, n_max) + y.shape[1:], dtype=y.dtype)
-    mask = np.zeros((k, n_max), dtype=np.float32)
-    for i, ix in enumerate(client_idx):
-        n = len(ix)
-        xs[i, :n] = x[ix]
-        ys[i, :n] = y[ix]
-        mask[i, :n] = 1.0
-        if n < n_max and n > 0:
-            xs[i, n:] = x[ix[0]]
-            ys[i, n:] = y[ix[0]]
+    sizes = np.array([len(ix) for ix in client_idx])
+    mask = (np.arange(n_max)[None, :] < sizes[:, None]).astype(np.float32)
+
+    def copy(i):
+        ix = np.asarray(client_idx[i])
+        if len(ix):
+            rows = np.concatenate([ix, np.full(n_max - len(ix), ix[0])])
+            np.take(x, rows, axis=0, out=xs[i])
+            np.take(y, rows, axis=0, out=ys[i])
+
+    with ThreadPoolExecutor(os.cpu_count() or 1) as pool:
+        list(pool.map(copy, range(k)))
     return xs, ys, mask

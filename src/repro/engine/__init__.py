@@ -22,10 +22,11 @@ One API, four orthogonal axes, three backends:
                      round chunks as one donated ``lax.scan``
                      (``FLConfig.fuse_rounds > 0``; selection fully
                      traced via ``select_mask_traced``)
-- ``scaleout``     — ``ScaleoutEngine``: the mesh round (clients blocked
-                     over the ``pod`` axis, shard_map + selection-
-                     weighted psum), plus ``make_scaleout_round`` for
-                     the production transformer mesh
+- ``scaleout``     — ``ScaleoutEngine``: the mesh round (client stacks
+                     sharded over the ``pod`` axis, the cohort gathered
+                     and trained over the pods, selection-weighted
+                     psum), plus ``make_scaleout_round`` for the
+                     production transformer mesh
 - ``aggregators``  — FedAvg / FedNova / FedDyn as stateful objects
 - ``client_modes`` — plain / FedProx / FedDyn gradient modifiers
 - ``tasks``        — the federated workload: ``classification`` (paper

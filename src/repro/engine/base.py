@@ -22,8 +22,9 @@ Backends implement the hooks:
   semantics where every client computes and the participation mask
   gates aggregation).
 - ``ScaleoutEngine`` (``repro.engine.scaleout``) — the same mask-gated
-  semantics at mesh scale: clients sharded over the ``pod`` axis via
-  shard_map, aggregation as the selection-weighted psum.
+  semantics at mesh scale: client stacks sharded over the ``pod`` axis,
+  the cohort trained over the pods via shard_map, aggregation as the
+  selection-weighted psum.
 - ``FusedEngine``    (``repro.engine.fused``)    — the compiled
   semantics with whole round *chunks* device-resident: one scanned jit
   per chunk, selection fully traced (``FLConfig.fuse_rounds``,
@@ -230,9 +231,7 @@ class Engine:
             )
             self.xs = self.ys = self.mask = None
         else:
-            self.xs, self.ys, self.mask = (
-                jnp.asarray(xs), jnp.asarray(ys), jnp.asarray(mask),
-            )
+            self.xs, self.ys, self.mask = self._place_clients(xs, ys, mask)
         self.test_x, self.test_y = jnp.asarray(test.x), jnp.asarray(test.y)
         self._train_data = train  # handed to the task when building fns
         self._test_data = test    # handed to the task for extra eval metrics
@@ -419,6 +418,22 @@ class Engine:
             self._test_data, self.n_classes
         )
 
+    def _place_clients(self, xs, ys, mask) -> tuple:
+        """Put the packed (K, N_max, ...) client stacks where the rounds
+        read them: the default device.  Backends whose stacks live on a
+        mesh place them there straight from the host."""
+        return jnp.asarray(xs), jnp.asarray(ys), jnp.asarray(mask)
+
+    def _device_params(self):
+        """The global parameters as the poll and the evaluation take
+        them: ``self.params`` itself on one device."""
+        return self.params
+
+    def _train_counters(self, sel: np.ndarray) -> dict:
+        """Counters the round's ``fl.train`` span carries as annotation
+        arguments (none by default)."""
+        return {}
+
     @staticmethod
     def _client_keys(key: jax.Array, indices) -> jax.Array:
         """Per-client PRNG keys derived by client index (``fold_in``), so
@@ -442,15 +457,15 @@ class Engine:
                     xs, ys, mask = self._store.gather(members)
                 out[members] = to_host(
                     self._poll_subset(
-                        self.params, xs, ys, mask,
+                        self._device_params(), xs, ys, mask,
                         jnp.asarray(members), key,
                     )
                 )
             return out
         if self.strategy.needs_losses:
-            return to_host(
-                self._poll_losses(self.params, self.xs, self.ys, self.mask, key)
-            )
+            return to_host(self._poll_losses(
+                self._device_params(), self.xs, self.ys, self.mask, key
+            ))
         return np.zeros(self.cfg.n_clients, np.float32)
 
     def _selection_gate(self, rnd: int) -> np.ndarray | None:
@@ -548,8 +563,10 @@ class Engine:
             self.last_quant_error = qerr
 
     def evaluate(self) -> tuple[float, float]:
-        tl, ta = to_host(self._evaluate(self.params, self.test_x, self.test_y),
-                         jax.device_get)
+        tl, ta = to_host(
+            self._evaluate(self._device_params(), self.test_x, self.test_y),
+            jax.device_get,
+        )
         return float(tl), float(ta)
 
     def eval_metrics(self) -> dict | None:
@@ -557,7 +574,7 @@ class Engine:
         task has none) — computed on the ``eval_every`` cadence only."""
         if self._eval_extra is None:
             return None
-        return self._eval_extra(self.params, self.test_x, self.test_y)
+        return self._eval_extra(self._device_params(), self.test_x, self.test_y)
 
     def _carry_key(self) -> jax.Array:
         """The persisted ``rounds()`` PRNG carry.  The stream from round
@@ -813,7 +830,7 @@ class Engine:
             surv = outcome.survivors
             n_reached = outcome.n_reached
             sim_time, n_dropped = outcome.sim_time, outcome.n_dropped
-            with span("train"):
+            with span("train", **self._train_counters(sel)):
                 payload, sel_losses = self.local_train(
                     rnd, sel, k_train, survivors=surv
                 )
@@ -821,7 +838,7 @@ class Engine:
             surv = sel
             n_reached = len(sel)
             sim_time, n_dropped = 0.0, 0
-            with span("train"):
+            with span("train", **self._train_counters(sel)):
                 payload, sel_losses = self.local_train(rnd, sel, k_train)
 
         n_faulty = n_quarantined = 0

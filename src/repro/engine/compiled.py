@@ -70,7 +70,25 @@ from repro.engine.base import Engine, MaskSelectionMixin
 from repro.engine.trace import scope, span, to_host
 from repro.federated.client import local_train
 
-__all__ = ["CompiledEngine", "make_scaleout_round"]
+__all__ = ["CompiledEngine", "cohort_trainer", "make_scaleout_round"]
+
+
+def cohort_trainer(apply_fn, loss_fn, *, lr: float, max_steps: int,
+                   batch_size: int, mu: float = 0.0):
+    """Plain local SGD of a stack of cohort clients, each from the same
+    global parameters: ``(params, xs, ys, mask, taus, keys) -> (ends,
+    losses)``, vmapped over the clients.  The cohort training of the
+    mask-gated backends (compiled, fused and scaleout) is this one
+    body."""
+
+    def one_client(global_params, x, y, mask, tau, key):
+        return local_train(
+            apply_fn, loss_fn, global_params, x, y, mask, tau, key,
+            lr=lr, max_steps=max_steps, batch_size=batch_size,
+            mode="plain", mu=mu, h_state=None,
+        )
+
+    return jax.vmap(one_client, in_axes=(None, 0, 0, 0, 0, 0))
 
 
 class CompiledEngine(MaskSelectionMixin, Engine):
@@ -96,17 +114,11 @@ class CompiledEngine(MaskSelectionMixin, Engine):
     # ------------------------------------------------------------------
     def _build_compiled_jits(self) -> None:
         cfg = self.cfg
-        apply_fn, loss_fn = self._apply_fn, self._loss_fn
         K = cfg.n_clients
-
-        def _one_client(global_params, x, y, mask, tau, key):
-            return local_train(
-                apply_fn, loss_fn, global_params, x, y, mask, tau, key,
-                lr=cfg.lr, max_steps=self.max_steps, batch_size=cfg.batch_size,
-                mode="plain", mu=cfg.mu, h_state=None,
-            )
-
-        vmapped = jax.vmap(_one_client, in_axes=(None, 0, 0, 0, 0, 0))
+        vmapped = cohort_trainer(
+            self._apply_fn, self._loss_fn, lr=cfg.lr, max_steps=self.max_steps,
+            batch_size=cfg.batch_size, mu=cfg.mu,
+        )
 
         def _train_all(params, xs, ys, mask, taus, key):
             with scope("train"):

@@ -27,9 +27,10 @@ SCOPES = ("poll", "select", "train", "aggregate")
 _NAMES = {name: "fl." + name for name in SPANS}
 
 
-def span(name: str) -> jax.profiler.TraceAnnotation:
-    """The host span ``fl.<name>``."""
-    return jax.profiler.TraceAnnotation(_NAMES[name])
+def span(name: str, **counters) -> jax.profiler.TraceAnnotation:
+    """The host span ``fl.<name>``; ``counters`` ride on it as the
+    annotation's arguments."""
+    return jax.profiler.TraceAnnotation(_NAMES[name], **counters)
 
 
 def step(name: str, num: int) -> jax.profiler.StepTraceAnnotation:

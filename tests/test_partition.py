@@ -93,3 +93,48 @@ def test_histograms_normalized():
     parts = dirichlet_partition(labels, 9, 0.2, seed=2)
     h = label_histograms(labels, parts, 7)
     np.testing.assert_allclose(h.sum(1), 1.0, atol=1e-9)
+
+
+@pytest.mark.parametrize("n,k,s,classes", [(10_000, 100, 1, range(10)),
+                                           (9_973, 37, 3, range(10)),
+                                           (5_000, 64, 8, (0, 2, 3, 7, 9))])
+def test_shard_histograms_match_the_built_partition(n, k, s, classes):
+    """The calibration's closed-form histograms are those of the
+    partition ``shard_partition`` builds from the same seed, exactly —
+    uneven shard sizes and classes absent from the data included — so
+    it picks the shard count a search over built partitions picks."""
+    from repro.data.partition import (_class_shuffled_order, _shard_histograms,
+                                      calibrate_shards, shard_partition)
+
+    labels = np.random.default_rng(n).choice(np.asarray(classes), n)
+    rng = np.random.default_rng(5)
+    _, bounds = _class_shuffled_order(labels, rng)
+    got = _shard_histograms(labels, k, s, 10, bounds, rng)
+    want = label_histograms(labels, shard_partition(labels, k, s, seed=5), 10)
+    np.testing.assert_array_equal(got, want)
+
+    def searched(target):
+        hds = {c: float(average_hd(label_histograms(
+            labels, shard_partition(labels, k, c, seed=5), 10)))
+            for c in (1, 2, 3, 4, 6, 8)}
+        return min(hds, key=lambda c: (abs(hds[c] - target), c))
+
+    for target in (0.9, 0.6, 0.3):
+        assert calibrate_shards(labels, k, target, 10, seed=5) == searched(target)
+
+
+def test_pack_clients_matches_a_client_by_client_copy():
+    """Packed on threads, every client's rows, padding and mask are the
+    plain loop's, empty clients included."""
+    rng = np.random.default_rng(4)
+    x = rng.standard_normal((500, 3, 2)).astype(np.float32)
+    y = rng.integers(0, 10, 500)
+    parts = [rng.choice(500, size, replace=False) for size in (7, 0, 31, 1, 31)]
+    xs, ys, mask = pack_clients(x, y, parts)
+    assert xs.shape == (5, 31, 3, 2) and ys.shape == (5, 31)
+    for i, ix in enumerate(parts):
+        pad = np.concatenate([ix, np.full(31 - len(ix), ix[0] if len(ix) else 0)])
+        want_x = x[pad] if len(ix) else np.zeros_like(xs[i])
+        np.testing.assert_array_equal(xs[i], want_x)
+        np.testing.assert_array_equal(ys[i], y[pad] if len(ix) else 0)
+        np.testing.assert_array_equal(mask[i], np.arange(31) < len(ix))
